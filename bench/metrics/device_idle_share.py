@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device,
+100 × (1 − busy / window), averaged over the chips, %."""
+
+
+def read(record):
+    t = record.get("trace")
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
