@@ -11,7 +11,6 @@ This benchmark records the payoff of the compile-once restructuring
   build in the process) and warm (a second build: the super-step path
   reuses every bucket-keyed compiled program; the eager path re-traces
   per exact level shape),
-* per-level super-step wall times (kind, fine n, seconds),
 * host-sync counts: batched decision fetches for the super-step path vs
   ``jax.device_get`` round-trips of the eager path,
 * the jit-cache hit/miss ledger across two *same-bucket* graphs (same
@@ -116,11 +115,6 @@ def bench_setup(scale: float = 0.12) -> dict:
         super_warm = time.perf_counter() - t0
         warm_counters = ss.counters()
 
-        # Per-level times come from a separate profiled run: profiling
-        # blocks per level, so it must not contaminate the warm timing.
-        levels: list = []
-        ss.build_hierarchy_superstep(adj, cfg_super, profile=levels)
-
         rows.append(dict(
             graph=name, n=n, nnz=nnz,
             levels_match=_level_sig(h_eager) == _level_sig(h_super),
@@ -136,8 +130,6 @@ def bench_setup(scale: float = 0.12) -> dict:
                               for s in cold_counters["steps"].values()),
             compiles_warm=sum(s["compiles"]
                               for s in warm_counters["steps"].values()),
-            per_level=[dict(kind=k, n_fine=nf, seconds=round(s, 4))
-                       for k, nf, s in levels],
         ))
 
     # --- zero-recompile check: a second same-bucket graph ----------------

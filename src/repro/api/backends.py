@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro import obs
 from repro.api.registry import register_backend
 
 
@@ -73,7 +74,7 @@ class _EagerHandle:
             precondition=self._options.precondition,
             exact_columns=self._options.exact_columns, x0=x0,
             guard=g or False, check=self._check)
-        return (np.asarray(X), info.residual_norms,
+        return (np.asarray(obs.fetch(X)), info.residual_norms,
                 np.asarray(info.iters, np.int64), info.status)
 
     def stats(self) -> dict:
@@ -155,7 +156,8 @@ class _DistHandle:
                                                        tol=tol)
             norms = np.asarray(norms)
             statuses = _norms_status(norms, tol, norms[0])
-        return (np.asarray(X), norms, np.asarray(iters, np.int64), statuses)
+        return (np.asarray(obs.fetch(X)), norms, np.asarray(iters, np.int64),
+                statuses)
 
     def stats(self) -> dict:
         import jax

@@ -42,6 +42,7 @@ import time
 
 import numpy as np
 
+from repro import obs
 from repro.api.cache import HierarchyCache
 from repro.api.options import SolverOptions
 from repro.api.problem import Problem
@@ -109,6 +110,7 @@ class Solver:
             return X, norms, iters, None
         return out
 
+    @obs.spanned("repro.solve")
     def solve(self, b, *, tol: float | None = None,
               max_iters: int | None = None, x0=None
               ) -> tuple[np.ndarray, SolveResult]:
@@ -196,6 +198,7 @@ class Solver:
         return (X[:, 0] if single else X), result
 
     # ------------------------------------------------------------------
+    @obs.spanned("repro.certify")
     def _certify(self, B, X, tol, norms, ref_norms):
         """Independent float64 certificate for the solve's claim, judged
         only on the columns that *claimed* convergence (an honest

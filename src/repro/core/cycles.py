@@ -22,6 +22,7 @@ from typing import Sequence, Union
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.coarsen import AggregationLevel
 from repro.core.elimination import EliminationLevel
 from repro.core.graph import GraphLevel
@@ -48,17 +49,26 @@ def _smooth(level: GraphLevel, b, x, sweeps: int, cfg: SmootherConfig, lam_max):
 
 def coarse_solve(coarse_inv: jax.Array, b: jax.Array) -> jax.Array:
     """Dense bottom solve via precomputed (L + α·J)⁻¹; result mean-free."""
-    x = coarse_inv @ b
-    return x - jnp.mean(x)
+    with obs.span("repro.vcycle.coarse"):
+        x = coarse_inv @ b
+        return x - jnp.mean(x)
 
 
 def cycle(transfers: Sequence[Transfer], lam_maxes: Sequence[jax.Array],
           coarse_inv: jax.Array, b: jax.Array, cfg: CycleConfig,
           k: int = 0) -> jax.Array:
-    """Apply one multigrid cycle to L_k x = b (x0 = 0). Returns x_k."""
+    """Apply one multigrid cycle to L_k x = b (x0 = 0). Returns x_k.
+
+    Level ``k``'s work, the coarser levels' included, runs inside a
+    ``repro.vcycle.L<k>`` span."""
     if k == len(transfers):
         return coarse_solve(coarse_inv, b)
+    with obs.span(f"repro.vcycle.L{k}"):
+        return _cycle_level(transfers, lam_maxes, coarse_inv, b, cfg, k)
 
+
+def _cycle_level(transfers, lam_maxes, coarse_inv, b, cfg: CycleConfig,
+                 k: int) -> jax.Array:
     t = transfers[k]
     if isinstance(t, EliminationLevel):
         # Exact elimination: no smoothing needed on this level (Schur).

@@ -16,6 +16,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.sparse import matvec as matvec_ops
 from repro.sparse.coo import COO, row_sums, spmv, degrees
 from repro.sparse.ell import ELL
@@ -47,8 +48,10 @@ class GraphLevel:
         return self.adj.n_rows
 
     def laplacian_matvec(self, x: jax.Array) -> jax.Array:
-        """L @ x = deg ⊙ x − A @ x (dispatches through repro.sparse.matvec)."""
-        return matvec_ops.laplacian_matvec(self, x)
+        """L @ x = deg ⊙ x − A @ x (dispatches through repro.sparse.matvec),
+        inside a ``repro.spmv`` span."""
+        with obs.span("repro.spmv"):
+            return matvec_ops.laplacian_matvec(self, x)
 
     def unweighted_degrees(self) -> jax.Array:
         return degrees(self.adj)

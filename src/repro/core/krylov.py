@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.testing import faults
 
 # Status codes reported by the eager solvers (SolveInfo.status and the
@@ -144,6 +145,7 @@ def _project(v):
     return v - jnp.mean(v)
 
 
+@obs.spanned("repro.pcg")
 def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
         x0: jax.Array | None = None, tol: float = 1e-8, maxiter: int = 500,
         project: Callable | None = None, guard=True, check=None):
@@ -167,7 +169,7 @@ def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
     z = proj(faults.site("solve.precond", M(r)))
     p = z
     rz = jnp.vdot(r, z)
-    r0n = float(jnp.linalg.norm(r))
+    r0n = float(obs.fetch(jnp.linalg.norm(r)))
     hist = [r0n]
     if r0n == 0:
         return x, SolveInfo(0, hist, True, STATUS_CONVERGED)
@@ -178,7 +180,7 @@ def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
         Ap = faults.site("solve.spmv", matvec(p))
         pAp = jnp.vdot(p, Ap)
         if check is not None:
-            pApf, bad = jax.device_get((pAp, check(p, Ap)))
+            pApf, bad = obs.fetch(pAp, check(p, Ap))
             if bool(bad):
                 # checksum mismatch: this Ap can't be trusted, freeze x at
                 # the last trusted iterate before the poisoned update
@@ -188,7 +190,7 @@ def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
                 if not math.isfinite(pApf) or pApf <= 0.0:
                     return x, SolveInfo(it, hist, False, STATUS_INDEFINITE)
         elif g is not None:
-            pApf = float(pAp)
+            pApf = float(obs.fetch(pAp))
             if not math.isfinite(pApf) or pApf <= 0.0:
                 # stop BEFORE applying the poisoned step: x is the last
                 # finite iterate, not a NaN field
@@ -196,7 +198,7 @@ def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
         alpha = rz / pAp
         x = x + alpha * p
         r = proj(faults.site("solve.residual", r - alpha * Ap))
-        rn = float(jnp.linalg.norm(r))
+        rn = float(obs.fetch(jnp.linalg.norm(r)))
         hist.append(rn)
         if rn <= tol * r0n:
             return x, SolveInfo(it + 1, hist, True, STATUS_CONVERGED)
@@ -218,6 +220,7 @@ def pcg(matvec: Callable, b: jax.Array, precond: Callable | None = None,
     return x, SolveInfo(maxiter, hist, False, STATUS_MAX_ITERS)
 
 
+@obs.spanned("repro.pcg")
 def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
               tol: float = 1e-8, maxiter: int = 500,
               exact_columns: bool = True, x0: jax.Array | None = None,
@@ -352,7 +355,7 @@ def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
     Z = proj(faults.site("solve.precond", bM(R, all_cols)))
     P = Z
     rz = cdot(R, Z)
-    r0n = np.asarray(jax.device_get(cnorm(R)))
+    r0n = np.asarray(obs.fetch(cnorm(R)))
     hist = [r0n]
     status = np.full(k, "", dtype="<U24")
     if x0 is None:
@@ -366,7 +369,7 @@ def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
         # warm starts measure against ||proj b|| (scipy's convention): a
         # column whose guess is already converged runs zero iterations
         # instead of chasing tol times its own tiny initial residual
-        ref = np.asarray(jax.device_get(cnorm(B)))
+        ref = np.asarray(obs.fetch(cnorm(B)))
         done0 = r0n <= tol * ref
     status[done0] = STATUS_CONVERGED
     active = ~done0
@@ -388,7 +391,7 @@ def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
         if check is not None:
             # one fused fetch covers both the checksum verdict and (when
             # guarded) the p·Ap read the indefinite guard needs anyway
-            pApf, sdc = jax.device_get((pAp, check(P, Ap)))
+            pApf, sdc = obs.fetch(pAp, check(P, Ap))
             bad = active & np.asarray(sdc)
             if bad.any():
                 # checksum mismatch: freeze the flagged columns at their
@@ -398,7 +401,7 @@ def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
                 if not active.any():
                     break
         if g is not None:
-            pApf = np.asarray(jax.device_get(pAp) if pApf is None else pApf)
+            pApf = np.asarray(obs.fetch(pAp) if pApf is None else pApf)
             bad = active & (~np.isfinite(pApf) | (pApf <= 0.0))
             if bad.any():
                 # freeze the broken columns BEFORE the update: their x stays
@@ -417,7 +420,7 @@ def pcg_block(matvec: Callable, B: jax.Array, precond: Callable | None = None,
         R = jnp.where(act[None, :],
                       proj(faults.site("solve.residual",
                                        R - alpha[None, :] * Ap)), R)
-        rn = np.asarray(jax.device_get(cnorm(R)))
+        rn = np.asarray(obs.fetch(cnorm(R)))
         hist.append(rn)
         just_done = active & (rn <= tol * ref)
         status[just_done] = STATUS_CONVERGED

@@ -75,11 +75,13 @@ affected rounds and keep building correctly on their own.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.aggregation import (aggregate, quantise_strength,
                                     renumber_device, vote_edge_reduce)
 from repro.core.coarsen import AggregationLevel, contract_arrays
@@ -98,14 +100,14 @@ from repro.testing import faults
 # ----------------------------------------------------------------------------
 
 _CACHE: dict = {}
-_STATS: dict = {}       # step name -> {"compiles": int, "calls": int}
-_SYNCS = [0]            # batched host fetches since the last reset
+# The compile/call/host-sync ledger lives in ``repro.obs`` under
+# ``setup.calls.<step>``, ``setup.compiles.<step>`` and ``setup.host_syncs``.
+_LEDGER = "setup."
 
 
 def reset_counters() -> None:
     """Zero the compile/call/host-sync counters (the cache stays warm)."""
-    _STATS.clear()
-    _SYNCS[0] = 0
+    obs.reset(_LEDGER)
 
 
 def clear_cache() -> None:
@@ -121,24 +123,39 @@ def counters() -> dict:
     ``jax.jit`` that only ever sees one set of shapes (its bucket), so a
     miss is exactly one XLA compile and a hit is a cache reuse.
     """
-    return dict(steps={k: dict(v) for k, v in _STATS.items()},
-                host_syncs=_SYNCS[0])
+    steps: dict = {}
+    host_syncs = 0
+    for name, n in obs.counters().items():
+        if not name.startswith(_LEDGER):
+            continue
+        kind, _, step = name[len(_LEDGER):].partition(".")
+        if kind == "host_syncs":
+            host_syncs = n
+        else:
+            steps.setdefault(step, dict(compiles=0, calls=0))[kind] = n
+    return dict(steps=steps, host_syncs=host_syncs)
 
 
 def _step(name: str, key, builder):
-    st = _STATS.setdefault(name, dict(compiles=0, calls=0))
-    st["calls"] += 1
+    """The registry program for ``(name, key)``, built on a miss; calling
+    it dispatches inside a ``repro.setup.<name>`` span."""
+    obs.count(f"{_LEDGER}calls.{name}")
     fn = _CACHE.get((name, key))
     if fn is None:
-        st["compiles"] += 1
+        obs.count(f"{_LEDGER}compiles.{name}")
         fn = _CACHE[(name, key)] = builder()
-    return fn
+    return functools.partial(_dispatch, f"repro.setup.{name}", fn)
+
+
+def _dispatch(span_name: str, fn, *args):
+    with obs.span(span_name):
+        return fn(*args)
 
 
 def _fetch(*vals):
     """One batched host sync for this decision point."""
-    _SYNCS[0] += 1
-    return jax.device_get(vals)
+    obs.count(f"{_LEDGER}host_syncs")
+    return obs.fetch(vals)
 
 
 def bucket(n: int, floor: int = 0) -> int:
@@ -577,7 +594,7 @@ def _validate_setup_cfg(cfg) -> None:
                          f"got {cfg.elim_sizing!r}")
 
 
-def _setup_plan(adj: COO, cfg, profile: list | None = None):
+def _setup_plan(adj: COO, cfg):
     """The setup loop as a *plan*: a generator yielding execution
     requests, returning the finished ``Hierarchy`` via ``StopIteration``.
 
@@ -643,14 +660,6 @@ def _setup_plan(adj: COO, cfg, profile: list | None = None):
         n_cap, e_cap, cur_n = n_to, e_to, n_c
         n_d = jnp.asarray(cur_n, jnp.int32)
 
-    def tick():
-        if profile is None:
-            return None
-        import time
-
-        jax.block_until_ready(deg_d)
-        return time.perf_counter()
-
     while cur_n > cfg.coarsest_size and len(specs) < cfg.max_levels:
         progressed = False
 
@@ -658,7 +667,6 @@ def _setup_plan(adj: COO, cfg, profile: list | None = None):
         for _ in range(cfg.elim_rounds_per_level):
             if cur_n <= cfg.coarsest_size:
                 break
-            t0 = tick()
             if cfg.elim_sizing == "conservative":
                 # Fused select+build; ONE batched decision fetch per elim
                 # level. A rejected pass wastes one speculative build —
@@ -690,15 +698,11 @@ def _setup_plan(adj: COO, cfg, profile: list | None = None):
             yield from advance(out["co_row"], out["co_col"], out["co_val"],
                                out["co_deg"], cur_n - n_elim, nnz_c)
             progressed = True
-            if profile is not None:
-                profile.append(("elim", specs[-1][1]["n"],
-                                tick() - t0))
 
         if cur_n <= cfg.coarsest_size:
             break
 
         # --- aggregation level -----------------------------------------
-        t0 = tick()
         out = yield ("step", "agg", (n_cap, e_cap),
                      (row_d, col_d, val_d, deg_d, n_d,
                       _lam_seed_vector(n_cap)))
@@ -715,8 +719,6 @@ def _setup_plan(adj: COO, cfg, profile: list | None = None):
         specs.append(("agg", dict(n=cur_n, n_c=n_c, nnz_c=nnz_c, out=out)))
         yield from advance(out["co_row"], out["co_col"], out["co_val"],
                            out["co_deg"], n_c, nnz_c)
-        if profile is not None:
-            profile.append(("agg", specs[-1][1]["n"], tick() - t0))
 
     # --- exact-shape wrap + dense bottom solve --------------------------
     level = graph_from_adjacency(adj)
@@ -753,15 +755,11 @@ def _exec_request(steps: SuperstepBuilders, req):
     return steps.step(method, params)(*args)
 
 
-def build_hierarchy_superstep(adj: COO, cfg, profile: list | None = None,
+def build_hierarchy_superstep(adj: COO, cfg,
                               steps: SuperstepBuilders | None = None):
     """Compile-once device-resident setup. Same contract (and an
     equivalent hierarchy: level sizes, kinds, PCG iteration counts) as
     ``core.hierarchy.build_hierarchy_eager``.
-
-    ``profile``: optional list; when given, each constructed level appends
-    ``(kind, n_fine, seconds)`` — the bench's per-level wall time. Timing
-    forces a block per level, so leave it ``None`` outside benchmarks.
 
     ``steps``: the super-step program factory; defaults to the serial
     :class:`SuperstepBuilders`. ``repro.dist.setup`` passes its
@@ -772,7 +770,7 @@ def build_hierarchy_superstep(adj: COO, cfg, profile: list | None = None,
     _validate_setup_cfg(cfg)
     if steps is None:
         steps = SuperstepBuilders(cfg)
-    plan = _setup_plan(adj, cfg, profile)
+    plan = _setup_plan(adj, cfg)
     payload = None
     while True:
         try:
