@@ -1,11 +1,31 @@
 """Multigrid cycles over a static hierarchy (paper §3: V(2,2)-cycle).
 
-The hierarchy is a Python list of transfer levels (static structure), so the
-recursion unrolls at trace time and the whole cycle jits into one XLA
-computation — the TPU analogue of the paper's fused MPI solve loop. W- and
-K-cycles (paper §4 future work) are provided as beyond-paper options: the
-K-cycle wraps the recursive correction in 2 steps of flexible CG, trading the
-paper's dot-product concern for TPU's cheap psums.
+The hierarchy is a Python list of transfer levels (static structure) and
+the recursion over it runs on the host, but each level's work runs
+compiled, as two XLA programs per level:
+
+* an aggregation level: ``_descend`` (pre-smooth, residual, restriction,
+  mean projection of the coarse RHS) and ``_ascend`` (prolongation,
+  correction, post-smooth);
+* an elimination level: ``_restrict`` and ``_prolong``;
+* the dense bottom solve: ``_coarse``.
+
+A V-cycle over T transfers is thus 2·T + 1 dispatches rather than one per
+jnp primitive. Levels, transfers, λmax and the coarse inverse are program
+*arguments* and only the smoother's configuration is static, so programs
+are keyed by shapes: a repeated solve, or a new hierarchy of the same
+shapes, compiles nothing. Each trace of a level program adds one to the
+``vcycle.compiles`` counter of ``repro.obs``. Under an outer trace
+(``pcg_scanned``, the dist scan, ``vmap``) the programs inline, so the
+whole cycle jits into one XLA computation there — the TPU analogue of the
+paper's fused MPI solve loop. ``jax.disable_jit()`` runs the same code
+eagerly, primitive by primitive.
+
+W- and K-cycles (paper §4 future work) are provided as beyond-paper
+options over the same level programs; the W-cycle's second-visit residual
+and the K-cycle's flexible-CG scalars run eagerly between them. The
+K-cycle wraps the recursive correction in 2 steps of flexible CG, trading
+the paper's dot-product concern for TPU's cheap psums.
 
 All per-level matvecs (smoothing, residuals, W/K-cycle corrections) go
 through ``GraphLevel.laplacian_matvec`` and hence the
@@ -17,6 +37,7 @@ the fused-kernel path inside ``_smooth``); plain levels stay on COO.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence, Union
 
 import jax
@@ -47,11 +68,57 @@ def _smooth(level: GraphLevel, b, x, sweeps: int, cfg: SmootherConfig, lam_max):
     return jacobi(level, b, x, n_sweeps=sweeps, omega=cfg.omega)
 
 
+def _level_program(*static_argnames: str):
+    """``jax.jit`` a level program, static in ``static_argnames`` alone;
+    each trace adds one to the ``vcycle.compiles`` counter."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def traced(*args, **kwargs):
+            obs.count("vcycle.compiles")
+            return fn(*args, **kwargs)
+        return jax.jit(traced, static_argnames=static_argnames)
+    return wrap
+
+
+@_level_program()
+def _coarse(coarse_inv: jax.Array, b: jax.Array) -> jax.Array:
+    x = coarse_inv @ b
+    return x - jnp.mean(x)
+
+
+@_level_program()
+def _restrict(t: EliminationLevel, b: jax.Array) -> jax.Array:
+    return t.restrict(b)
+
+
+@_level_program()
+def _prolong(t: EliminationLevel, x_c: jax.Array, b: jax.Array) -> jax.Array:
+    return t.prolong(x_c, b)
+
+
+@_level_program("sm")
+def _descend(t: AggregationLevel, lam_max, b: jax.Array, *,
+             sm: SmootherConfig) -> tuple:
+    """Pre-smooth from x = 0, restrict the residual; returns ``(x, r_c)``
+    with ``r_c`` mean-free (in range(L_c))."""
+    level = t.fine
+    x = _smooth(level, b, jnp.zeros_like(b), sm.pre_sweeps, sm, lam_max)
+    r_c = t.restrict(b - level.laplacian_matvec(x))
+    return x, r_c - jnp.mean(r_c)
+
+
+@_level_program("sm")
+def _ascend(t: AggregationLevel, lam_max, b: jax.Array, x: jax.Array,
+            x_c: jax.Array, *, sm: SmootherConfig) -> jax.Array:
+    """Add the prolonged coarse correction, then post-smooth."""
+    x = x + t.prolong(x_c)
+    return _smooth(t.fine, b, x, sm.post_sweeps, sm, lam_max)
+
+
 def coarse_solve(coarse_inv: jax.Array, b: jax.Array) -> jax.Array:
     """Dense bottom solve via precomputed (L + α·J)⁻¹; result mean-free."""
     with obs.span("repro.vcycle.coarse"):
-        x = coarse_inv @ b
-        return x - jnp.mean(x)
+        return _coarse(coarse_inv, b)
 
 
 def cycle(transfers: Sequence[Transfer], lam_maxes: Sequence[jax.Array],
@@ -59,7 +126,7 @@ def cycle(transfers: Sequence[Transfer], lam_maxes: Sequence[jax.Array],
           k: int = 0) -> jax.Array:
     """Apply one multigrid cycle to L_k x = b (x0 = 0). Returns x_k.
 
-    Level ``k``'s work, the coarser levels' included, runs inside a
+    Level ``k``'s programs, and the coarser levels, run inside a
     ``repro.vcycle.L<k>`` span."""
     if k == len(transfers):
         return coarse_solve(coarse_inv, b)
@@ -72,17 +139,12 @@ def _cycle_level(transfers, lam_maxes, coarse_inv, b, cfg: CycleConfig,
     t = transfers[k]
     if isinstance(t, EliminationLevel):
         # Exact elimination: no smoothing needed on this level (Schur).
-        b_c = t.restrict(b)
-        x_c = cycle(transfers, lam_maxes, coarse_inv, b_c, cfg, k + 1)
-        return t.prolong(x_c, b)
+        x_c = cycle(transfers, lam_maxes, coarse_inv, _restrict(t, b), cfg,
+                    k + 1)
+        return _prolong(t, x_c, b)
 
-    level = t.fine
     sm = cfg.smoother
-    x = jnp.zeros_like(b)
-    x = _smooth(level, b, x, sm.pre_sweeps, sm, lam_maxes[k])
-    r = b - level.laplacian_matvec(x)
-    r_c = t.restrict(r)
-    r_c = r_c - jnp.mean(r_c)  # keep coarse RHS in range(L_c)
+    x, r_c = _descend(t, lam_maxes[k], b, sm=sm)
 
     n_recurse = 1 if cfg.kind == "V" or k + 1 >= len(transfers) else 2
     if cfg.kind == "K" and k + 1 < len(transfers):
@@ -93,9 +155,7 @@ def _cycle_level(transfers, lam_maxes, coarse_inv, b, cfg: CycleConfig,
             r2 = r_c - t.coarse.laplacian_matvec(x_c)
             x_c = x_c + cycle(transfers, lam_maxes, coarse_inv, r2, cfg, k + 1)
 
-    x = x + t.prolong(x_c)
-    x = _smooth(level, b, x, sm.post_sweeps, sm, lam_maxes[k])
-    return x
+    return _ascend(t, lam_maxes[k], b, x, x_c, sm=sm)
 
 
 def _fcg_accelerated(transfers, lam_maxes, coarse_inv, b, cfg: CycleConfig, k: int):

@@ -10,7 +10,9 @@
   ``jax.device_get`` inside a ``repro.sync`` span, so each host sync is
   counted and shows in a trace.
 * :func:`count`, :func:`counters` and :func:`reset` keep named counts
-  (``repro.core.setup_step`` keeps its compile/call/sync ledger here).
+  (``repro.core.setup_step`` keeps its compile/call/sync ledger here;
+  ``repro.core.cycles`` counts each trace of a level program as
+  ``vcycle.compiles``).
 
 Spans only observe: they record nothing while JAX traces a function (under
 ``jit``, ``vmap`` or a scan), so a compiled program holds no trace of them,
