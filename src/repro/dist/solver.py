@@ -30,6 +30,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core.cycles import CycleConfig, cycle
 from repro.core.elimination import EliminationLevel
 from repro.core.graph import GraphLevel, graph_from_adjacency
@@ -290,18 +291,19 @@ def _pcg_block_init(matvec, B, precond, n: int, n_pad: int, guard=None):
     return base + (code0, best0, jnp.zeros((k,), jnp.int32), r0n)
 
 
-def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
-                     length: int, carry, guard=None, check=None):
-    """Advance a blocked PCG carry ``length`` scan steps.
+def _pcg_block_step(matvec, precond, n: int, n_pad: int, tol: float, r0n,
+                    guard=None, check=None):
+    """One blocked PCG step and the test that a column still needs one.
 
-    Each step carries a residual-based active mask: once a column's
-    residual norm drops below ``tol * ||r0||`` its alpha is zeroed and its
-    residual pinned, so x/r stop updating while the scan (fixed shapes,
-    fixed length — the jit/dry-run contract) carries the remaining columns.
-    ``tol=0`` reproduces the original never-exit behavior.
+    Returns ``(running, step)``: ``running(state) -> bool[k]`` and
+    ``step(state) -> (state, norms [k])`` over the carry without its
+    trailing ``r0n``. A column is active while its residual norm is above
+    ``tol * ||r0||``; an inactive column's alpha is zeroed and its residual
+    pinned, so x/r stop updating while the other columns go on. ``tol=0``
+    keeps every column with a nonzero residual active.
 
     With ``guard`` a ``GuardConfig`` (carry from the guarded init), the
-    PR 8 breakdown guards run *inside* the scan per column: an indefinite
+    PR 8 breakdown guards run *inside* the step per column: an indefinite
     or non-finite ``p·Ap`` freezes the column BEFORE the poisoned update
     (x stays the last finite iterate, exactly like eager ``pcg_block``), a
     non-finite residual norm freezes it after, and ``stagnation_window``
@@ -320,18 +322,16 @@ def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
     ``SCAN_SDC`` before the poisoned update, ahead of the indefinite
     guard — the verdict is a pure extra lane, so clean trajectories stay
     bitwise identical with the check on.
-
-    Returns ``(carry, norms [length, k])``; ``carry[5]`` counts the steps
-    each column was active for, cumulative across chunks.
     """
     bmv, bM, proj, cnorm = _block_ops(matvec, precond, n, n_pad)
 
     if guard is None:
-        r0n = carry[6]
+        def running(state):
+            return cnorm(state[1]) > tol * r0n
 
-        def body(state, _):
+        def step(state):
             X, R, Z, P, rz, iters = state
-            active = cnorm(R) > tol * r0n
+            active = running(state)
             iters = iters + active.astype(jnp.int32)
             Ap = bmv(P)
             pAp = jnp.sum(P * Ap, axis=0)
@@ -346,16 +346,16 @@ def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
             P = Z + beta[None, :] * P
             return (X, R, Z, P, rz_new, iters), cnorm(R)
 
-        state, norms = jax.lax.scan(body, tuple(carry[:6]), None,
-                                    length=length)
-        return state + (r0n,), norms
+        return running, step
 
     g = guard
-    r0n = carry[9]
 
-    def gbody(state, _):
+    def grunning(state):
+        return (cnorm(state[1]) > tol * r0n) & (state[6] == SCAN_OK)
+
+    def gstep(state):
         X, R, Z, P, rz, iters, code, best, stall = state
-        active = (cnorm(R) > tol * r0n) & (code == SCAN_OK)
+        active = grunning(state)
         Ap = faults.site_traced("dist.spmv", bmv(P))
         if check is not None:
             sdc = active & check(P, Ap)
@@ -379,7 +379,7 @@ def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
         stalled = active & (stall >= g.stagnation_window)
         code = jnp.where(stalled, SCAN_STAGNATION, code)
         active = active & ~stalled
-        # the tail is op-for-op the unguarded body (bitwise parity on
+        # the tail is op-for-op the unguarded step (bitwise parity on
         # clean paths); frozen columns meet zeroed betas, and a broken
         # column's NaN rz can never reach X (its alpha selects 0)
         Z = jnp.where(active[None, :], proj(bM(R)), Z)
@@ -388,8 +388,41 @@ def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
         P = Z + beta[None, :] * P
         return (X, R, Z, P, rz_new, iters, code, best, stall), rn
 
-    state, norms = jax.lax.scan(gbody, tuple(carry[:9]), None, length=length)
-    return state + (r0n,), norms
+    return grunning, gstep
+
+
+def _pcg_block_chunk(matvec, precond, n: int, n_pad: int, tol: float,
+                     length: int, carry, guard=None, check=None):
+    """Advance a blocked PCG carry by at most ``length`` steps of
+    :func:`_pcg_block_step`, stopping as soon as no column is active.
+
+    The loop is a ``lax.while_loop``: a block that converges after 6 steps
+    runs 6, not ``length``. A step run past that point would change
+    nothing (every alpha zero, every residual pinned), so X, iters and the
+    norms of the steps that ran are those of a fixed-length scan.
+
+    Returns ``(carry, norms [length, k], steps)``: ``steps`` (int32) is the
+    number of steps run, and only the first ``steps`` rows of ``norms``
+    are written (the rest are zero). ``carry[5]`` counts the steps each
+    column was active for, cumulative across chunks.
+    """
+    r0n = carry[-1]
+    running, step = _pcg_block_step(matvec, precond, n, n_pad, tol, r0n,
+                                    guard=guard, check=check)
+
+    def cond(loop):
+        i, _, state = loop
+        return (i < length) & jnp.any(running(state))
+
+    def body(loop):
+        i, norms, state = loop
+        state, rn = step(state)
+        return i + 1, norms.at[i].set(rn), state
+
+    norms0 = jnp.zeros((length, r0n.shape[0]), r0n.dtype)
+    steps, norms, state = jax.lax.while_loop(
+        cond, body, (jnp.zeros((), jnp.int32), norms0, tuple(carry[:-1])))
+    return state + (r0n,), norms, steps
 
 
 def _partition_level(level: GraphLevel, mesh, matvec_backend: str = "coo",
@@ -410,6 +443,8 @@ def _partition_level(level: GraphLevel, mesh, matvec_backend: str = "coo",
     adj = level.adj
     row, col, val, valid = jax.device_get(
         (adj.row, adj.col, adj.val, adj.valid))
+    obs.count("dist.partition_host_bytes",
+              sum(a.nbytes for a in (row, col, val, valid)))
     # A corrupted upstream setup (fault injection, overflowed aggregate
     # ids) can leave vertex ids outside [0, n). Those edges are
     # structurally impossible — drop them here so the damage surfaces as
@@ -498,8 +533,9 @@ class DistLaplacianSolver:
         vals = np.asarray(vals, np.float32)
         perm = inv_perm = None
         if random_ordering:
-            rows, cols, perm, inv_perm = random_relabel(
-                n, rows, cols, setup_config.seed)
+            with obs.span("repro.dist.setup.relabel"):
+                rows, cols, perm, inv_perm = random_relabel(
+                    n, rows, cols, setup_config.seed)
 
         adj = to_laplacian_coo(n, rows, cols, vals)
         # Build the hierarchy without replicated ELL twins: the largest
@@ -520,7 +556,8 @@ class DistLaplacianSolver:
         if setup_config.setup_mode == "superstep":
             from repro.dist.setup import build_hierarchy_superstep_dist
 
-            h = build_hierarchy_superstep_dist(adj, setup_cfg, mesh)
+            with obs.span("repro.dist.setup.superstep"):
+                h = build_hierarchy_superstep_dist(adj, setup_cfg, mesh)
         else:
             h = build_hierarchy(adj, setup_cfg)
 
@@ -536,10 +573,11 @@ class DistLaplacianSolver:
                 break
             if nnz < dist_nnz_threshold:
                 break
-            dfine, fill, blocks = _partition_level(
-                t.fine, mesh, matvec_backend=setup_config.matvec_backend,
-                ell_width_percentile=setup_config.ell_width_percentile,
-                ell_width_cap=setup_config.ell_width_cap)
+            with obs.span("repro.dist.setup.partition"):
+                dfine, fill, blocks = _partition_level(
+                    t.fine, mesh, matvec_backend=setup_config.matvec_backend,
+                    ell_width_percentile=setup_config.ell_width_percentile,
+                    ell_width_cap=setup_config.ell_width_cap)
             dist_transfers.append(dataclasses.replace(t, fine=dfine))
             lam_maxes.append(lam)
             level_meta.append(DistLevelMeta(
@@ -607,7 +645,8 @@ class DistLaplacianSolver:
 
     def build_chunk_step(self, length: int, tol: float = 0.0, guard=None,
                          check=None):
-        """(arrays, coarse_h, carry) -> (carry, norms [length, k])."""
+        """(arrays, coarse_h, carry) -> (carry, norms [length, k], steps):
+        at most ``length`` steps, ending once every column is done."""
         n, n_pad = self.n, self.n_pad
 
         def step(arrays, coarse_h, carry):
@@ -621,10 +660,13 @@ class DistLaplacianSolver:
                                guard=None, check=None):
         """(arrays, coarse_h, B_pad [n_pad, k]) -> (X_pad, norms, iters).
 
-        One fused program — init + full-length scan — so a dry-run lowering
-        sees every collective of the solve phase in a single HLO. With
-        ``guard`` a ``GuardConfig`` the in-scan status lanes run and the
-        return grows a fourth element: per-column int32 ``SCAN_*`` codes.
+        One fused program — init + one chunk of ``n_iters`` steps — so a
+        dry-run lowering sees every collective of the solve phase in a
+        single HLO. ``norms`` always has ``n_iters + 1`` rows: once every
+        column is done the loop ends, and the rows after it repeat the last
+        one, as further steps would. With ``guard`` a ``GuardConfig`` the
+        in-loop status lanes run and the return grows a fourth element:
+        per-column int32 ``SCAN_*`` codes.
         """
         init = self.build_init_step(guard=guard)
         chunk = self.build_chunk_step(n_iters, tol=tol, guard=guard,
@@ -633,8 +675,10 @@ class DistLaplacianSolver:
         def step(arrays, coarse_h, B_pad):
             carry = init(arrays, coarse_h, B_pad)
             r0n = carry[-1]
-            carry, norms = chunk(arrays, coarse_h, carry)
+            carry, norms, steps = chunk(arrays, coarse_h, carry)
             norms = jnp.concatenate([r0n[None, :], norms], axis=0)
+            ran = jnp.arange(n_iters + 1)[:, None] <= steps
+            norms = jnp.where(ran, norms, norms[steps][None, :])
             if guard is None:
                 return carry[0], norms, carry[5]
             return carry[0], norms, carry[5], carry[6]
@@ -668,15 +712,15 @@ class DistLaplacianSolver:
         freezes; pass ``tol=0`` for the fixed-iteration behavior).
 
         Returns (x [n], norms [T+1]) with T <= n_iters (the solve stops at
-        the first chunk boundary after convergence).
+        the step where it converged).
         """
         b = jnp.asarray(b, jnp.float32)
         X, norms, _ = self.solve_block(b[:, None], n_iters=n_iters, tol=tol)
         return X[:, 0], norms[:, 0]
 
-    # chunk length for the eager solve path: long enough that compiles and
-    # host round-trips amortise, short enough that a solve converging in
-    # tens of iterations never pays hundreds (the scan itself cannot exit).
+    # chunk length for the eager solve path: the host checks the iteration
+    # cap and the statuses once per chunk; a chunk itself ends as soon as
+    # every column is done.
     _CHUNK = 16
 
     def _get_step(self, key, build):
@@ -694,25 +738,27 @@ class DistLaplacianSolver:
             step = self._steps[key] = jax.jit(build())
         return step
 
+    @obs.spanned("repro.pcg")
     def solve_block(self, B, n_iters: int = 30, tol: float = 1e-8,
                     guard=None, check=None):
         """Blocked multi-RHS distributed solve: ``B`` is (n, k).
 
-        All k columns ride one scanned PCG program — the 2D-sharded SpMV
+        All k columns ride one compiled PCG loop — the 2D-sharded SpMV
         and V-cycle collectives run once per iteration for the whole block.
-        With ``tol > 0`` the scan runs in chunks of ``_CHUNK`` iterations
-        and stops at the first chunk boundary where every column has
+        With ``tol > 0`` the loop runs in chunks of at most ``_CHUNK``
+        iterations, and each chunk ends at the step where every column has
         converged, so a generous ``n_iters`` cap costs nothing once the
-        block is done. Returns (X [n, k], norms [T+1, k], iters [k]) with
-        T <= n_iters.
+        block is done. The host fetches once after the init and once per
+        chunk. Returns (X [n, k], norms [T+1, k], iters [k]) with T <=
+        n_iters the steps that ran.
 
         ``guard`` (bool or ``repro.core.krylov.GuardConfig``) turns on the
         in-scan breakdown lanes: the return grows a fourth element — the
         per-column int32 ``SCAN_*`` codes, fetched live from the carry —
-        and broken columns also count as done for the early chunk exit
-        (a fully-broken block stops at the next chunk boundary instead of
-        burning the whole iteration cap). Clean-path X/norms/iters are
-        bitwise identical to the unguarded program.
+        and broken columns also count as done for the early exit (a
+        fully-broken block stops at once instead of burning the whole
+        iteration cap). Clean-path X/norms/iters are bitwise identical to
+        the unguarded program.
 
         ``check`` is an ABFT checksum closure over *padded* (P, Ap) blocks
         (``repro.core.verify.make_check`` on the padded degree vector);
@@ -734,34 +780,44 @@ class DistLaplacianSolver:
 
         init = self._get_step(("init", k, g),
                               lambda: self.build_init_step(guard=g))
-        carry = init(self.arrays, self.coarse_h, B_pad)
-        r0n = np.asarray(jax.device_get(carry[-1]))
+        with obs.span("repro.dist.init"):
+            carry = init(self.arrays, self.coarse_h, B_pad)
+        r0n = np.asarray(obs.fetch(carry[-1]))
 
-        # small caps run as one program (one compile, the old behavior);
-        # chunking only pays once the cap is far beyond typical convergence
+        # a cap far beyond typical convergence runs as chunks of _CHUNK
+        # steps (one program, one fetch each); a small cap as one chunk
         chunked = tol > 0 and n_iters > 2 * self._CHUNK
         norms_parts = [r0n[None, :]]
         it = 0
-        while it < n_iters:
+        while True:
             length = min(self._CHUNK, n_iters - it) if chunked else n_iters
             key = ("chunk", k, length, tol, g, check)
             step = self._get_step(
                 key, lambda: self.build_chunk_step(length, tol=tol, guard=g,
                                                    check=check))
-            carry, ns = step(self.arrays, self.coarse_h, carry)
-            norms_parts.append(np.asarray(jax.device_get(ns)))
+            with obs.span("repro.dist.chunk"):
+                carry, ns, steps = step(self.arrays, self.coarse_h, carry)
+            lanes = (ns, steps, carry[5]) + ((carry[6],) if g is not None
+                                             else ())
+            ns, steps, iters, *codes = obs.fetch(*lanes)
+            steps = int(steps)
+            obs.count("dist.chunks")
+            obs.count("dist.scan_steps", steps)
+            norms_parts.append(np.asarray(ns)[:steps])
             it += length
-            if tol > 0:
-                done = norms_parts[-1][-1] <= tol * r0n
-                if g is not None:
-                    done = done | (np.asarray(jax.device_get(carry[6])) !=
-                                   SCAN_OK)
-                if np.all(done):
-                    break
-        X_pad, iters = carry[0], carry[5]
-        norms = np.concatenate(norms_parts, axis=0)
-        out = (self._from_internal(X_pad[: self.n]), norms,
-               np.asarray(jax.device_get(iters)))
+            if steps < length or it >= n_iters:
+                break
+            # a chunk that ran its full length may have ended just as the
+            # last column finished: then no further chunk is launched
+            done = norms_parts[-1][-1] <= tol * r0n
+            if g is not None:
+                done = done | (np.asarray(codes[0]) != SCAN_OK)
+            if tol > 0 and np.all(done):
+                break
+        iters = np.asarray(iters)
+        obs.count("dist.pcg_iters", int(iters.max(initial=0)))
+        out = (self._from_internal(carry[0][: self.n]),
+               np.concatenate(norms_parts, axis=0), iters)
         if g is not None:
-            out = out + (np.asarray(jax.device_get(carry[6])),)
+            out = out + (np.asarray(codes[0]),)
         return out

@@ -23,7 +23,13 @@ Span names, each read by a benchmark metric (``PERF.md`` section 3):
 ``repro.spmv`` (``GraphLevel.laplacian_matvec``), ``repro.vcycle.L<k>``
 and ``repro.vcycle.coarse`` (one multigrid level, the dense bottom solve),
 ``repro.sync`` (a host fetch), ``repro.certify`` (the float64 certificate)
-and ``repro.setup.<step>`` (one set-up super-step dispatch).
+and ``repro.setup.<step>`` (one set-up super-step dispatch). The dist
+backend adds ``repro.dist.init`` and ``repro.dist.chunk`` (one launch of
+its compiled PCG init and loop, under ``repro.pcg``) and
+``repro.dist.setup.relabel`` / ``.superstep`` / ``.partition`` (its set-up
+phases), and counts ``dist.chunks``, ``dist.scan_steps`` (PCG steps its
+loop ran), ``dist.pcg_iters`` (each solve's largest column iteration
+count) and ``dist.partition_host_bytes``.
 """
 
 from __future__ import annotations
